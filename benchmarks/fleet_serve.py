@@ -59,6 +59,7 @@ from repro.launch.serving import (FleetConfig, FleetLearner,
                                   O2ServiceConfig, ServeConfig,
                                   TuningService)
 from repro.launch.serving.programs import _step_program
+from repro.runtime import compile_cache
 
 
 def make_requests(n: int, n_keys: int, seed: int = 1):
@@ -194,6 +195,7 @@ def stack_microbench(cfg: LITuneConfig, fleet: FleetConfig, k: int,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tenants", default="64,128,256,512",
                     metavar="N1,N2,...",

@@ -42,10 +42,11 @@ from repro.index import alex
 from repro.index.workloads import sample_keys, wr_workload
 from repro.kernels import dispatch
 from repro.kernels.dispatch import KernelConfig
-from repro.kernels.index_probe.ops import _auto_tile, batched_lookup
+from repro.kernels.index_probe.ops import auto_tile, probe_ranks
 from repro.launch.serving import O2ServiceConfig, ServeConfig, TuningService
 from repro.launch.serving.programs import (_capture_write, _pow2_ladder,
                                            _step_program)
+from repro.runtime import compile_cache
 
 POSTURES = ("ref", "interpret", "compiled")
 
@@ -74,7 +75,7 @@ def bench_probe(n_keys: int, n_queries: int, n_timing: int) -> list[dict]:
     key = jax.random.PRNGKey(0)
     keys = jnp.sort(jax.random.uniform(key, (n_keys,)))
     queries = jax.random.uniform(jax.random.fold_in(key, 1), (n_queries,))
-    tile = _auto_tile(n_keys)
+    tile = auto_tile(n_keys)
     rows = []
 
     ss = jax.jit(lambda k, q: jnp.clip(
@@ -88,9 +89,8 @@ def bench_probe(n_keys: int, n_queries: int, n_timing: int) -> list[dict]:
             rows.append({"impl": f"pallas_{mode}", "skipped": skip})
             continue
         us = _time_us(
-            lambda: batched_lookup(keys, queries, tile=tile,
-                                   qcap=n_queries,
-                                   mode=mode)[0].block_until_ready(),
+            lambda: probe_ranks(keys, queries, tile=tile,
+                                mode=mode)[0].block_until_ready(),
             n_timing)
         rows.append({"impl": f"pallas_{mode}", "us_per_call": round(us, 1)})
     return rows
@@ -199,6 +199,7 @@ def bench_tick(slots: int, budget: int, n_keys: int, ticks: int,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--n-keys", type=int, default=4096)
     ap.add_argument("--n-queries", type=int, default=512)

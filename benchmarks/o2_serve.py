@@ -75,6 +75,7 @@ from repro.launch.serving import (DeviceSlice, O2ServiceConfig,
                                   ServeConfig, ServingTopology,
                                   TuningService)
 from repro.launch.serving.topology import _largest_divisor_leq
+from repro.runtime import compile_cache
 
 
 def make_requests(n: int, n_keys: int, seed: int = 1):
@@ -165,6 +166,7 @@ def annex_sweep(mk_tuner, requests, budget: int, slots: int,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--budget", type=int, default=16)

@@ -103,7 +103,8 @@ def _kernel_record(name: str, shape: str, jitted, args, kwargs=None) -> dict:
     # no 6ND notion for data-movement kernels: the HLO flops *are* the
     # model flops, so useful_ratio pins at 1.0 and the interesting
     # numbers are bytes, arithmetic intensity, and the dominant term
-    terms = ha.roofline(analysis, analysis.flops)
+    kind = ha.modelled_kind(jax.devices()[0])
+    terms = ha.roofline(analysis, kind, analysis.flops)
     arg_bytes = sum(x.nbytes for x in jax.tree.leaves((args, kwargs))
                     if hasattr(x, "nbytes"))
     ai = (analysis.flops / analysis.bytes_accessed
@@ -114,6 +115,7 @@ def _kernel_record(name: str, shape: str, jitted, args, kwargs=None) -> dict:
         "arithmetic_intensity": round(ai, 4),
         "hlo_analysis": analysis.as_dict(),
         "roofline": {
+            "device_kind": kind,
             "compute_s": terms.compute_s,
             "memory_s": terms.memory_s,
             "collective_s": terms.collective_s,
@@ -141,7 +143,7 @@ def kernel_roofline(out: str | None = None) -> list[str]:
     from repro.index.workloads import sample_keys, wr_workload
     from repro.kernels.fused_tick.ops import fused_capture
     from repro.kernels.fused_tick.ref import FIELD_ORDER, fused_capture_ref
-    from repro.kernels.index_probe.ops import _auto_tile, batched_lookup
+    from repro.kernels.index_probe.ops import auto_tile, probe_ranks
     from repro.launch.serving import (O2ServiceConfig, ServeConfig,
                                       TuningService)
     from repro.launch.serving.programs import _pow2_ladder, _step_program
@@ -153,10 +155,10 @@ def kernel_roofline(out: str | None = None) -> list[str]:
     n, q = 4096, 512
     keys = jnp.sort(jax.random.uniform(key, (n,)))
     queries = jax.random.uniform(jax.random.fold_in(key, 1), (q,))
-    tile = _auto_tile(n)
+    tile = auto_tile(n)
     for mode in ("ref", "interpret"):        # compiled needs a real accel
-        fn = jax.jit(lambda k, qq, _m=mode: batched_lookup(
-            k, qq, tile=tile, qcap=q, mode=_m))
+        fn = jax.jit(lambda k, qq, _m=mode: probe_ranks(
+            k, qq, tile=tile, mode=_m))
         records.append(_kernel_record(
             "index_probe", f"n{n} q{q} t{tile} {mode}", fn, (keys, queries)))
 
@@ -232,6 +234,9 @@ def kernel_roofline(out: str | None = None) -> list[str]:
 
 if __name__ == "__main__":
     import argparse
+
+    from repro.runtime import compile_cache
+    compile_cache.enable()
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default=None, metavar="PATH",

@@ -15,8 +15,11 @@ import sys
 import time
 import traceback
 
+from repro.runtime import compile_cache
+
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--only", default="",
                     help="comma-separated benchmark names")

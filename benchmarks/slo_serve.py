@@ -96,6 +96,7 @@ from repro.core.litune import LITune, LITuneConfig
 from repro.index.workloads import sample_keys, wr_workload
 from repro.launch.serving import (AdaptiveSlotPolicy, EDFSlotPolicy,
                                   ServeConfig, TuningService)
+from repro.runtime import compile_cache
 
 
 def make_arrivals(n_bursts: int, burst_mean: int, gap_s: float,
@@ -480,6 +481,7 @@ def run_chaos(args):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--bursts", type=int, default=4)
     ap.add_argument("--burst-mean", type=int, default=8,

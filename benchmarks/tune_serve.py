@@ -38,6 +38,7 @@ import jax
 from repro.core.litune import LITune, LITuneConfig
 from repro.index.workloads import sample_keys, wr_workload
 from repro.launch.serving import ServeConfig, TuningService
+from repro.runtime import compile_cache
 
 
 def make_requests(n: int, n_keys: int, seed: int = 1, mixed_wr: bool = False):
@@ -76,6 +77,7 @@ def bench_batched(tuner: LITune, requests, budget: int, slots: int) -> float:
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--budget", type=int, default=8)

@@ -29,7 +29,8 @@ def evaluate(cfg, shape, mesh, rules):
     plan = plan_cell(cfg, shape, mesh, rules_override=rules)
     compiled = lower_cell(plan).compile()
     analysis = ha.analyze(compiled.as_text(), n_devices=mesh.size)
-    terms = ha.roofline(analysis, plan.bundle.model_flops(shape) / mesh.size)
+    terms = ha.roofline(analysis, ha.modelled_kind(mesh.devices.flat[0]),
+                        plan.bundle.model_flops(shape) / mesh.size)
     return terms
 
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.index import cost as C
 from repro.index import linear_model as lm
+from repro.index import order
 from repro.kernels.index_probe.ops import predecessor_positions
 
 MAX_LEAVES = 512  # static capacity; max_fanout param stays below this
@@ -80,7 +81,7 @@ def build(keys: jax.Array, p: dict):
     # root model: linear fit of key -> leaf id (exact for equi-width)
     root_slope_w = n_leaves / width
     root_icpt_w = -root_slope_w * kmin
-    rs, ri, _ = lm.fit_segments_exact(keys, jnp.zeros_like(seg), 1)
+    rs, ri, _ = lm.fit_root(keys)
     root_slope_d = rs[0] * n_leaves / nf        # rank-model -> leaf id
     root_icpt_d = ri[0] * n_leaves / nf
     root_slope = jnp.where(equi_width, root_slope_w, root_slope_d)
@@ -153,7 +154,7 @@ def run_reads(idx: dict, reads: jax.Array, kernel=None):
     total = jnp.sum(per_q)
     return total, {
         "avg_search_dist": jnp.mean(search_dist),
-        "p99_search_dist": jnp.percentile(search_dist, 99),
+        "p99_search_dist": order.percentile(search_dist, 99),
         "avg_root_err": jnp.mean(root_err),
         "read_ns_avg": jnp.mean(per_q),
     }

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.index import cost as C
 from repro.index import linear_model as lm
+from repro.index import order
 from repro.kernels.index_probe.ops import predecessor_positions
 
 MAX_LEAVES = 1024
@@ -66,7 +67,7 @@ def build(keys: jax.Array, p: dict):
     slope, intercept, cnt = lm.fit_segments_exact(keys, seg, MAX_LEAVES)
     err = lm.segment_errors(keys, seg, MAX_LEAVES, slope, intercept)
 
-    rs, ri, _ = lm.fit_segments_exact(keys, jnp.zeros_like(seg), 1)
+    rs, ri, _ = lm.fit_root(keys)
     root_slope = rs[0] * n_leaves / nf
     root_icpt = ri[0] * n_leaves / nf
 
@@ -122,7 +123,7 @@ def run_reads(idx, reads, p, kernel=None):
     total = jnp.sum(per_q)
     return total, {
         "avg_search_dist": jnp.mean(dist),
-        "p99_search_dist": jnp.percentile(dist, 99),
+        "p99_search_dist": order.percentile(dist, 99),
         "avg_root_err": jnp.mean(root_err),
         "read_ns_avg": jnp.mean(per_q),
     }

@@ -37,6 +37,18 @@ def fit_segments_exact(keys: jax.Array, seg_id: jax.Array, n_segs: int):
     return slope, intercept, cnt
 
 
+def fit_root(keys: jax.Array):
+    """One least-squares model over all keys (the root model's fit).
+
+    The all-zero segment ids pass through an optimization barrier: with
+    constant ids XLA constant-folds the count and rank-sum scatters at
+    compile time, which takes the TPU compiler about 3.5 s each at 2^20
+    keys (v5e, ahead-of-time compile) — in every program that builds an
+    index.  The barrier leaves the arithmetic as it is."""
+    seg = jax.lax.optimization_barrier(jnp.zeros(keys.shape, jnp.int32))
+    return fit_segments_exact(keys, seg, 1)
+
+
 def fit_segments_approx(keys: jax.Array, seg_id: jax.Array, n_segs: int):
     """2-point (min/max) fit per segment — ALEX's approximate model path."""
     big = jnp.inf

@@ -17,6 +17,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.index import order
+
 DATASETS = ("uniform", "books", "osm", "fb", "mix")
 
 
@@ -54,7 +56,7 @@ def sample_keys(key: jax.Array, n: int, dist: str = "mix",
         raise ValueError(f"unknown dataset {dist}")
     # dedupe-ish: add tiny deterministic jitter, normalize, sort
     x = _normalize(x) + jnp.arange(n) * 1e-12
-    return jnp.sort(_normalize(x))
+    return order.sort(_normalize(x))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -86,8 +86,9 @@ class KernelConfig:
     resolved mode is a Pallas one; ``fused_tick`` gates fusing the
     K-ladder tick's transition-capture tail into the serving step
     program (`launch/serving/programs._step_program(capture=True)`);
-    ``probe_tile`` overrides the probe kernel's key-tile size (0 = the
-    largest power-of-two divisor of n, capped at 512).
+    ``probe_tile`` overrides the probe kernel's key-tile size (0 =
+    `index_probe.ops.auto_tile`: 512, or the smallest power of two
+    covering a smaller n; the last tile is padded with +inf keys).
     """
 
     mode: str = "auto"

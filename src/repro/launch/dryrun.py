@@ -107,9 +107,11 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         analysis = ha.analyze(compiled.as_text(), n_devices=n_dev,
                               fused_scopes=fused_scopes)
         model_flops_dev = plan.bundle.model_flops(shape) / n_dev
-        terms = ha.roofline(analysis, model_flops_dev)
+        kind = ha.modelled_kind(mesh.devices.flat[0])
+        terms = ha.roofline(analysis, kind, model_flops_dev)
         record["hlo_analysis"] = analysis.as_dict()
         record["roofline"] = {
+            "device_kind": kind,
             "compute_s": terms.compute_s,
             "memory_s": terms.memory_s,
             "collective_s": terms.collective_s,

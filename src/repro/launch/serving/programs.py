@@ -45,7 +45,6 @@ from repro.core.replay import donate_argnums
 from repro.kernels.fused_tick.ops import fused_capture_core
 from repro.kernels.fused_tick.ref import fused_capture_ref
 from repro.launch.serving.topology import DeviceSlice, _slice_mesh
-from repro.runtime.mesh_utils import shard_map_compat
 
 
 def _pow2_ladder(n: int) -> list[int]:
@@ -130,14 +129,14 @@ def _step_program(slice_: DeviceSlice, net_cfg, env_cfg, et_cfg, k: int,
                                       kmode)
             return c2, out, cap2
 
-        return jax.jit(shard_map_compat(
-            core, mesh, in_specs=(p_spec, P(ax), P(ax), P(ax), P(ax)),
-            out_specs=(P(ax), P(None, ax), P(ax))),
+        return jax.jit(jax.shard_map(
+            core, mesh=mesh, in_specs=(p_spec, P(ax), P(ax), P(ax), P(ax)),
+            out_specs=(P(ax), P(None, ax), P(ax)), check_vma=False),
             donate_argnums=donate_argnums(1, 3))
 
-    return jax.jit(shard_map_compat(
-        scan_core, mesh, in_specs=(p_spec, P(ax), P(ax)),
-        out_specs=(P(ax), P(None, ax))),
+    return jax.jit(jax.shard_map(
+        scan_core, mesh=mesh, in_specs=(p_spec, P(ax), P(ax)),
+        out_specs=(P(ax), P(None, ax)), check_vma=False),
         donate_argnums=donate_argnums(1))
 
 
@@ -151,10 +150,10 @@ def _reset_program(slice_: DeviceSlice, env_cfg):
     def core(d, r, i, wr):
         return mapped_reset(env_cfg, d, {"reads": r, "inserts": i}, wr)
 
-    return jax.jit(shard_map_compat(
-        core, mesh,
+    return jax.jit(jax.shard_map(
+        core, mesh=mesh,
         in_specs=(P(ax), P(ax), P(ax), P(ax)),
-        out_specs=P(ax)))
+        out_specs=P(ax), check_vma=False))
 
 
 @lru_cache(maxsize=None)

@@ -46,6 +46,7 @@ from repro.launch.serving.slo import SLOConfig, SLOTracker
 from repro.launch.serving.stats import (PoolStats, SchedulerStats,
                                         ServiceStats)
 from repro.launch.serving.topology import ServingTopology
+from repro.runtime import compile_cache
 
 
 class TuningService:
@@ -657,6 +658,7 @@ class TuningService:
 
 # ---------------------------------------------------------------- driver
 def main():
+    compile_cache.enable()
     from repro.core.litune import LITune, LITuneConfig
     from repro.index.workloads import sample_keys, wr_workload
 
@@ -669,8 +671,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    cfg = LITuneConfig(index_type=args.index, episode_len=args.budget,
-                       lstm_hidden=32, mlp_hidden=64)
+    # the paper agent's widths (LITuneConfig defaults: LSTM 128, MLP 256)
+    cfg = LITuneConfig(index_type=args.index, episode_len=args.budget)
     tuner = LITune(cfg, seed=args.seed)
     service = TuningService(tuner, config=ServeConfig(slots=args.slots,
                                                       seed=args.seed))

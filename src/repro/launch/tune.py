@@ -19,9 +19,11 @@ import jax
 
 from repro.core.litune import LITune, LITuneConfig
 from repro.index.workloads import StreamConfig, sample_keys, stream_windows, wr_workload
+from repro.runtime import compile_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--index", default="alex", choices=["alex", "carmi"])
     ap.add_argument("--dataset", default="mix",

@@ -150,9 +150,9 @@ def apply_moe_shard_map(p: dict, x_normed: jax.Array, cfg: ArchConfig,
 
     batch_spec = P(data_axes if len(data_axes) > 1 else data_axes[0])
     expert_spec = P(mesh_utils.MODEL_AXIS)
-    fn = mesh_utils.shard_map_compat(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(), expert_spec, expert_spec, expert_spec, batch_spec),
         out_specs=(batch_spec, P()),
-        axis_names={mesh_utils.MODEL_AXIS, *data_axes})
+        axis_names={mesh_utils.MODEL_AXIS, *data_axes}, check_vma=False)
     return fn(p["router"], p["wg"], p["wu"], p["wd"], x)

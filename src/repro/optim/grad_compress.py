@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.mesh_utils import shard_map_compat
 
 
 def quantize(x: jax.Array):
@@ -96,7 +95,7 @@ def make_pod_grad_fn(loss_fn, mesh, params_tree, batch_tree,
         loss = jax.lax.pmean(loss, axis_name)
         return loss, grads, new_err
 
-    return shard_map_compat(body, mesh,
-                            in_specs=(p_specs, e_specs, b_specs),
-                            out_specs=(P(), p_specs, e_specs),
-                            axis_names={axis_name})
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(p_specs, e_specs, b_specs),
+                         out_specs=(P(), p_specs, e_specs),
+                         axis_names={axis_name}, check_vma=False)

@@ -438,10 +438,47 @@ def analyze(text: str, n_devices: int = 1,
 
 
 # ------------------------------------------------------------------
-# TPU v5e roofline constants (per chip)
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # B/s
-ICI_BW = 50e9                  # B/s per link
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one device kind."""
+    flops_bf16: float              # FLOP/s
+    hbm_bw: float                  # B/s
+    ici_bw: float                  # B/s per ICI link (ring collectives
+    #                                use one link per direction)
+    source: str
+
+
+# keyed by `jax.Device.device_kind`; a kind that is not here has no
+# peaks, and asking for them is an error rather than a silent default
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        flops_bf16=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8 / 4,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s interconnect per "
+               "chip over its 4 ICI links (2D torus)"),
+}
+
+# HLO lowered on CPU host devices (the 512-device dry runs, the kernel
+# roofline on a CPU) stands in for a chip the CPU has no peaks for; its
+# roofline is modelled for this kind, and says so in its records
+CPU_MODELLED_KIND = "TPU v5 lite"
+
+
+def modelled_kind(device) -> str:
+    """The `PEAKS` key for a program compiled for `device`: the device's
+    own kind on an accelerator (an unknown one then raises in `peaks`),
+    `CPU_MODELLED_KIND` where the HLO was lowered for the CPU."""
+    if device.platform == "cpu":
+        return CPU_MODELLED_KIND
+    return device.device_kind
+
+
+def peaks(device_kind: str) -> DevicePeaks:
+    """The peak table entry for a device kind (raises for unknown)."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 
 @dataclasses.dataclass
@@ -452,6 +489,7 @@ class RooflineTerms:
     collective_wire_s: float
     model_flops: float = 0.0
     hlo_flops: float = 0.0
+    peak_flops: float = 0.0
 
     @property
     def dominant(self) -> str:
@@ -473,16 +511,21 @@ class RooflineTerms:
         """MODEL_FLOPS-per-chip / peak over the bottleneck-implied step time."""
         if self.step_time_s == 0:
             return 0.0
-        return (self.model_flops / PEAK_FLOPS_BF16) / self.step_time_s
+        return (self.model_flops / self.peak_flops) / self.step_time_s
 
 
-def roofline(analysis: HLOAnalysis, model_flops_per_device: float = 0.0
-             ) -> RooflineTerms:
+def roofline(analysis: HLOAnalysis, device_kind: str,
+             model_flops_per_device: float = 0.0) -> RooflineTerms:
+    """Roofline terms of `analysis` against the peaks of `device_kind`
+    (a `PEAKS` key: the kind the program is modelled for, which need not
+    be the kind that compiled it)."""
+    pk = peaks(device_kind)
     return RooflineTerms(
-        compute_s=analysis.flops / PEAK_FLOPS_BF16,
-        memory_s=analysis.bytes_accessed / HBM_BW,
-        collective_s=analysis.collective_bytes / ICI_BW,
-        collective_wire_s=analysis.collective_wire_bytes / ICI_BW,
+        compute_s=analysis.flops / pk.flops_bf16,
+        memory_s=analysis.bytes_accessed / pk.hbm_bw,
+        collective_s=analysis.collective_bytes / pk.ici_bw,
+        collective_wire_s=analysis.collective_wire_bytes / pk.ici_bw,
         model_flops=model_flops_per_device,
         hlo_flops=analysis.flops,
+        peak_flops=pk.flops_bf16,
     )

@@ -20,28 +20,6 @@ DATA_AXES = ("pod", "data")
 MODEL_AXIS = "model"
 
 
-def shard_map_compat(body, mesh, in_specs, out_specs, axis_names=None):
-    """Version-tolerant `shard_map` (single shim for every call site):
-    jax >= 0.6 exposes `jax.shard_map` with `check_vma`/`axis_names`;
-    older versions only have the experimental surface with `check_rep`.
-    `axis_names` (the *manual* axes) maps to the experimental surface's
-    complementary `auto=` set so partial-manual programs keep their
-    GSPMD-managed axes instead of silently going fully manual."""
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": False}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map
-    kw = {"check_rep": False}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)
-
 # Default logical-axis -> mesh-axis rules (single source of truth).
 # None means replicate.  Tuples mean "shard over the product of these axes".
 DEFAULT_RULES: dict[str, object] = {
@@ -152,17 +130,12 @@ def constrain(x, logical_axes, mesh=None, rules=None):
 
 
 def _current_mesh():
-    try:
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        try:
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
-            return None if mesh.empty else mesh
-        except Exception:
-            return None
+    """The mesh of the enclosing `with mesh:` block, None outside one
+    (`jax.sharding.get_mesh` sees only `jax.set_mesh`, not the context
+    manager the launchers use)."""
+    from jax._src import mesh as mesh_lib
+    mesh = mesh_lib.thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
 
 
 @dataclasses.dataclass(frozen=True)

@@ -129,6 +129,22 @@ def test_alex_run_reads_kernel_mode_parity(rng_key):
                                       np.asarray(m_k[f]), err_msg=f)
 
 
+def test_alex_run_reads_kernel_mode_parity_ragged_keys(rng_key):
+    """A key count with no power-of-two tile (2000 = 16 * 125) runs the
+    padded kernel and still equals the reference path bitwise."""
+    keys = jnp.sort(jax.random.uniform(rng_key, (2000,)))
+    reads = jax.random.uniform(jax.random.fold_in(rng_key, 1), (256,)) \
+        * 1.4 - 0.2
+    idx = alex.build(keys, _alex_params())
+    ns_ref, m_ref = alex.run_reads(idx, reads)
+    ns_k, m_k = alex.run_reads(idx, reads,
+                               kernel=KernelConfig(mode="interpret"))
+    np.testing.assert_array_equal(np.asarray(ns_ref), np.asarray(ns_k))
+    for f in m_ref:
+        np.testing.assert_array_equal(np.asarray(m_ref[f]),
+                                      np.asarray(m_k[f]), err_msg=f)
+
+
 def test_carmi_run_reads_kernel_mode_parity(rng_key):
     p = _carmi_params()
     keys = jnp.sort(jax.random.uniform(rng_key, (2048,)))

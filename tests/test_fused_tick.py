@@ -40,13 +40,31 @@ def test_fused_capture_interpret_matches_ref(seed, k_steps, b):
     new = {f: jax.random.normal(ks[i], (k_steps, b, dims[f]), jnp.float32)
            for i, f in enumerate(FIELD_ORDER)}
     cap = jax.random.normal(ks[-2], (b, h, wide), jnp.float32)
-    offsets = jax.random.randint(ks[-1], (b,), 0, h - k_steps + 1)
+    # offsets past h - k_steps exercise the clamp both sides share
+    offsets = jax.random.randint(ks[-1], (b,), 0, h + 2)
     got = fused_capture(cap, new, offsets, mode="interpret")
     want = fused_capture(cap, new, offsets, mode="ref")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # and the oracle really is the historical _capture_write body
     direct = fused_capture_ref(cap, new, offsets.astype(jnp.int32))
     np.testing.assert_array_equal(np.asarray(want), np.asarray(direct))
+
+
+def test_fused_capture_interpret_at_serving_width():
+    """The serving shape the chip compiles: 4 slots, the paper agent's
+    128-wide LSTM (wide 564), a K=8 rung of a 16-step horizon."""
+    key = jax.random.PRNGKey(0)
+    b, h, k_steps = 4, 16, 8
+    dims = {"obs": 26, "next_obs": 26, "h_a": 128, "c_a": 128,
+            "h_q": 128, "c_q": 128}
+    ks = jax.random.split(key, len(FIELD_ORDER) + 1)
+    new = {f: jax.random.normal(ks[i], (k_steps, b, dims[f]), jnp.float32)
+           for i, f in enumerate(FIELD_ORDER)}
+    cap = jax.random.normal(ks[-1], (b, h, sum(dims.values())), jnp.float32)
+    offsets = jnp.asarray([0, 3, h - k_steps, h], jnp.int32)
+    got = fused_capture(cap, new, offsets, mode="interpret")
+    want = fused_capture(cap, new, offsets, mode="ref")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_fused_capture_field_order_matches_replay():

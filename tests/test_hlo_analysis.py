@@ -1,6 +1,8 @@
 """HLO parser validation: scan-based totals must match XLA's own
 cost_analysis on an unrolled twin, and trip counts must come from the
 trip_scope markers."""
+import types
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -77,14 +79,24 @@ def test_bytes_sane(compiled_pair):
 
 
 def test_roofline_terms():
+    pk = ha.peaks("TPU v5 lite")
     a = ha.HLOAnalysis(flops=197e12, bytes_accessed=819e9,
-                       collective_bytes=50e9)
-    t = ha.roofline(a, model_flops_per_device=98.5e12)
+                       collective_bytes=pk.ici_bw)
+    t = ha.roofline(a, "TPU v5 lite", model_flops_per_device=98.5e12)
     assert abs(t.compute_s - 1.0) < 1e-6
     assert abs(t.memory_s - 1.0) < 1e-6
     assert abs(t.collective_s - 1.0) < 1e-6
     assert t.useful_ratio == pytest.approx(0.5)
     assert t.dominant in ("compute", "memory", "collective")
+    with pytest.raises(KeyError, match="no published peaks"):
+        ha.roofline(a, "cpu")
+    # CPU-lowered HLO is modelled for an explicit kind; an accelerator's
+    # own kind is used as is, so an unknown one raises
+    cpu = types.SimpleNamespace(platform="cpu", device_kind="cpu")
+    assert ha.modelled_kind(cpu) == ha.CPU_MODELLED_KIND in ha.PEAKS
+    gpu = types.SimpleNamespace(platform="gpu", device_kind="H100")
+    with pytest.raises(KeyError, match="no published peaks"):
+        ha.roofline(a, ha.modelled_kind(gpu))
 
 
 def test_collective_parsing_small_mesh():

@@ -9,8 +9,10 @@ from _hypothesis_compat import given, settings, st
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.index_probe.kernel import probe_pallas
-from repro.kernels.index_probe.ops import (batched_lookup,
-                                           predecessor_positions)
+from repro.kernels.index_probe.ops import (auto_ocap, auto_qcap,
+                                           auto_tile, batched_lookup,
+                                           predecessor_positions,
+                                           probe_ranks)
 from repro.kernels.dispatch import KernelConfig
 from repro.kernels.index_probe.ref import probe_ref
 from repro.kernels.mamba_scan.kernel import selective_scan
@@ -28,7 +30,7 @@ MODES = ["ref", "interpret",
 
 # ------------------------------------------------------------ index probe
 @pytest.mark.parametrize("n_tiles,tile,qcap", [
-    (4, 128, 32), (8, 256, 16), (2, 512, 64), (16, 64, 8)])
+    (4, 128, 32), (8, 256, 16), (2, 512, 64), (16, 64, 8), (12, 256, 128)])
 def test_probe_matches_ref(n_tiles, tile, qcap, rng_key):
     keys = jnp.sort(jax.random.uniform(rng_key, (n_tiles * tile,))
                     ).reshape(n_tiles, tile)
@@ -181,9 +183,11 @@ def test_batched_lookup_capacity_retry_recovers(rng_key):
        st.sampled_from(["float32", "float64"]))
 def test_batched_lookup_mode_parity(mode, seed, tile, dtype):
     """Every execution mode agrees with searchsorted on duplicate-heavy
-    keys and out-of-range queries, across tiles and dtypes."""
+    keys and out-of-range queries, across tiles, dtypes and ragged key
+    counts."""
     key = jax.random.PRNGKey(seed)
-    n = 8 * tile
+    # whole tiles and a ragged tail (padded with +inf keys)
+    n = 8 * tile - seed % 3 * 5
     # integer-valued keys -> runs of duplicates, some spanning tiles
     keys = jnp.sort(jax.random.randint(key, (n,), 0, n // 4)
                     ).astype(dtype)
@@ -200,10 +204,11 @@ def test_batched_lookup_mode_parity(mode, seed, tile, dtype):
 
 @pytest.mark.parametrize("mode", MODES)
 @settings(max_examples=8, deadline=None)
-@given(st.integers(0, 10_000), st.sampled_from([512, 768, 2048]))
+@given(st.integers(0, 10_000), st.sampled_from([512, 768, 2048, 1001]))
 def test_predecessor_positions_mode_parity(mode, seed, n):
     """The env-facing probe equals clip(searchsorted-1) in every mode —
-    including n=768 where the auto tile is 256, not the 512 cap."""
+    including n=768 and a ragged n=1001, which pad their last 512-key
+    tile with +inf keys."""
     key = jax.random.PRNGKey(seed)
     keys = jnp.sort(jax.random.randint(key, (n,), 0, n // 2)
                     ).astype(jnp.float32)
@@ -215,13 +220,23 @@ def test_predecessor_positions_mode_parity(mode, seed, n):
 
 
 def test_predecessor_positions_ragged_falls_back(rng_key):
-    """Array lengths with no usable pow2 divisor take the searchsorted
-    fallback (still exact) instead of asserting inside batched_lookup."""
+    """Array lengths with no usable pow2 divisor no longer fall back to
+    searchsorted under a Pallas mode: the last tile is padded with +inf
+    keys and the kernel answers (still exact)."""
     keys = jnp.sort(jax.random.uniform(rng_key, (1001,)))   # odd length
     q = jax.random.uniform(jax.random.fold_in(rng_key, 1), (32,))
+    assert auto_tile(1001) == 512                # 2 tiles, 23 pad keys
+    # one rule for every n: 512-key tiles (a power of two covering a
+    # smaller n), never a small divisor of n
+    assert auto_tile(8 * 125_001) == auto_tile(768) == 512
+    assert (auto_tile(5), auto_tile(100), auto_tile(256)) == (8, 128, 256)
     got = predecessor_positions(keys, q, kernel=KernelConfig(mode="interpret"))
     want = jnp.clip(jnp.searchsorted(keys, q, side="right") - 1, 0, 1000)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    ranks, n_over = probe_ranks(keys, q, tile=512, mode="interpret")
+    assert int(n_over) == 0                      # every query in-kernel
+    np.testing.assert_array_equal(
+        np.asarray(ranks), np.asarray(jnp.searchsorted(keys, q, side="right")))
     # probe_reads=False forces the reference regardless of mode
     got_off = predecessor_positions(
         jnp.sort(jax.random.uniform(rng_key, (1024,))), q,
@@ -229,3 +244,47 @@ def test_predecessor_positions_ragged_falls_back(rng_key):
     keys2 = jnp.sort(jax.random.uniform(rng_key, (1024,)))
     want2 = jnp.clip(jnp.searchsorted(keys2, q, side="right") - 1, 0, 1023)
     np.testing.assert_array_equal(np.asarray(got_off), np.asarray(want2))
+
+
+@pytest.mark.parametrize("n_crammed", [200, 400])
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+def test_probe_ranks_resolves_overflow_exactly(mode, n_crammed, rng_key):
+    """Queries beyond a tile's bounded capacity are answered by
+    searchsorted in overflow chunks and counted; every rank stays exact —
+    in one chunk (200 crammed) and in several (400 crammed)."""
+    tile = 128
+    keys = jnp.sort(jax.random.uniform(rng_key, (8 * tile,)))
+    # queries crammed into tile 0's range, 40 spread: qcap=128 drops
+    crammed = jnp.linspace(float(keys[1]), float(keys[tile - 2]),
+                           n_crammed)
+    spread = jax.random.uniform(jax.random.fold_in(rng_key, 2), (40,))
+    q = jnp.concatenate([crammed, spread])
+    _, dropped = batched_lookup(keys, q, tile=tile, qcap=128, mode=mode)
+    ranks, n_over = probe_ranks(keys, q, tile=tile, qcap=128, mode=mode)
+    assert int(n_over) == int(np.asarray(dropped).sum()) >= n_crammed - 128
+    # one chunk holds the overflow, or it takes several
+    assert (int(n_over) <= auto_ocap(q.shape[0])) == (n_crammed == 200)
+    np.testing.assert_array_equal(
+        np.asarray(ranks), np.asarray(jnp.searchsorted(keys, q, side="right")))
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+def test_probe_ranks_nonfinite_queries(mode):
+    """NaN queries rank after every key (searchsorted's order), +/-inf
+    queries at the ends, with and without +inf pad keys."""
+    keys = jnp.sort(jnp.concatenate([jnp.linspace(-1.0, 1.0, 250),
+                                     jnp.array([jnp.inf] * 3)]))
+    q = jnp.array([jnp.nan, jnp.inf, -jnp.inf, 0.0, -0.0, 1.0, 5.0])
+    ranks, _ = probe_ranks(keys, q, tile=128, mode=mode)
+    np.testing.assert_array_equal(
+        np.asarray(ranks), np.asarray(jnp.searchsorted(keys, q, side="right")))
+
+
+def test_auto_qcap_keeps_grouped_buffers_linear():
+    """At 2^20 keys the grouped buffers are O(n + m), not O(n * m): the
+    buffers that used to take 6 GiB each at the read-heavy mix."""
+    n_tiles = (1 << 20) // auto_tile(1 << 20)
+    for m in (786_432, 524_288, 262_144):
+        qcap = auto_qcap(m, n_tiles)
+        assert qcap % 128 == 0
+        assert n_tiles * qcap <= 2 * m + 128 * n_tiles
