@@ -26,13 +26,10 @@ def main() -> None:
     ap.add_argument("--skip", default="", help="comma-separated names")
     args = ap.parse_args()
 
-    from benchmarks import paper_figs, roofline
+    from benchmarks import paper_figs
     from benchmarks.common import SCALE
 
     benches = dict(paper_figs.ALL)
-    benches["micro_steps"] = roofline.micro_steps
-    benches["kernel_micro"] = roofline.kernel_micro
-    benches["kernel_roofline"] = roofline.kernel_roofline
 
     only = [s for s in args.only.split(",") if s]
     skip = set(s for s in args.skip.split(",") if s)

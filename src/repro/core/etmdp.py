@@ -65,13 +65,14 @@ def _episode_step_core(params, carry, noise_scale, net_cfg,
     (termination reward substituted on early exit), `outputs["early"]` the
     budget-exceeded flag, `outputs["done"]` early-or-horizon.
     """
-    key, k_act = jax.random.split(carry["key"])
-    action, h_a2 = ddpg.act(params, carry["obs"], carry["h_a"], k_act,
-                            net_cfg, noise_scale=noise_scale,
-                            deterministic=deterministic)
-    # critic hidden advances on (obs, action) for stored-state replay
-    _, h_q2 = nets.critic_apply(params["critic0"], carry["obs"], action,
-                                carry["h_q"], net_cfg)
+    with jax.named_scope("agent"):
+        key, k_act = jax.random.split(carry["key"])
+        action, h_a2 = ddpg.act(params, carry["obs"], carry["h_a"], k_act,
+                                net_cfg, noise_scale=noise_scale,
+                                deterministic=deterministic)
+        # critic hidden advances on (obs, action) for stored-state replay
+        _, h_q2 = nets.critic_apply(params["critic0"], carry["obs"],
+                                    action, carry["h_q"], net_cfg)
     env2, next_obs, r, done, info = E.step_core(env_cfg, carry["env"], action)
     cost = info["cost"]
     b_t = carry["b_t"] + cost

@@ -67,24 +67,23 @@ def evaluate_params(cfg: EnvConfig, params_raw: dict, data_keys, workload,
     primitive shared by the RL env and every baseline tuner.
     """
     mod = _backend(cfg.index_type)
-    if cfg.index_type == "alex":
+    read_args = () if cfg.index_type == "alex" else (params_raw,)
+    with jax.named_scope("env_build"):
         idx = mod.build(data_keys, params_raw)
-        read_ns, read_m = mod.run_reads(idx, workload["reads"],
+    with jax.named_scope("env_reads"):
+        read_ns, read_m = mod.run_reads(idx, workload["reads"], *read_args,
                                         kernel=cfg.kernel)
+    with jax.named_scope("env_inserts"):
         idx, ins_ns, ins_m = mod.run_inserts(idx, workload["inserts"],
                                              params_raw)
-    else:
-        idx = mod.build(data_keys, params_raw)
-        read_ns, read_m = mod.run_reads(idx, workload["reads"], params_raw,
-                                        kernel=cfg.kernel)
-        idx, ins_ns, ins_m = mod.run_inserts(idx, workload["inserts"],
-                                             params_raw)
-    n_ops = workload["reads"].shape[0] + workload["inserts"].shape[0]
-    runtime = (read_ns + ins_ns) / n_ops  # avg ns per operation (paper metric)
-    mem = mod.memory_bytes(idx, params_raw) if cfg.index_type == "alex" \
-        else mod.memory_bytes(idx)
-    c_m = (mem > cfg.mem_budget).astype(jnp.float32)
-    c_r = ((read_ns + ins_ns) > cfg.runtime_budget).astype(jnp.float32)
+    with jax.named_scope("env_observe"):
+        n_ops = workload["reads"].shape[0] + workload["inserts"].shape[0]
+        # avg ns per operation (paper metric)
+        runtime = (read_ns + ins_ns) / n_ops
+        mem = mod.memory_bytes(idx, params_raw) \
+            if cfg.index_type == "alex" else mod.memory_bytes(idx)
+        c_m = (mem > cfg.mem_budget).astype(jnp.float32)
+        c_r = ((read_ns + ins_ns) > cfg.runtime_budget).astype(jnp.float32)
     return runtime, (idx, read_m, ins_m), {"c_m": c_m, "c_r": c_r,
                                            "memory_bytes": mem}
 
@@ -97,8 +96,9 @@ def reset(cfg: EnvConfig, data_keys, workload, wr_ratio,
         k: jnp.float32(v) for k, v in mod.DEFAULTS.items()}
     r0, (idx, read_m, ins_m), viol = evaluate_params(
         cfg, default_raw, data_keys, workload, wr_ratio)
-    ws = workload_stats(data_keys, wr_ratio)
-    obs = state_vector(idx, read_m, ins_m, r0, r0, r0, ws)
+    with jax.named_scope("env_observe"):
+        ws = workload_stats(data_keys, wr_ratio)
+        obs = state_vector(idx, read_m, ins_m, r0, r0, r0, ws)
     env_state = {
         "data_keys": data_keys,
         "reads": workload["reads"],
@@ -120,16 +120,18 @@ def step_core(cfg: EnvConfig, env_state: dict, action: jax.Array):
     (`launch/tune_serve.py`) `lax.map`s it over a slot axis.
     """
     space = cfg.space
-    params_raw = space.decode(action)
+    with jax.named_scope("env_build"):
+        params_raw = space.decode(action)
     workload = {"reads": env_state["reads"], "inserts": env_state["inserts"]}
     runtime, (idx, read_m, ins_m), viol = evaluate_params(
         cfg, params_raw, env_state["data_keys"], workload,
         env_state["wr_ratio"])
-    r = rw.reward(runtime, env_state["r0"], env_state["r_prev"],
-                  cfg.omega, cfg.kappa)
-    ws = workload_stats(env_state["data_keys"], env_state["wr_ratio"])
-    obs = state_vector(idx, read_m, ins_m, runtime, env_state["r_prev"],
-                       env_state["r0"], ws)
+    with jax.named_scope("env_observe"):
+        r = rw.reward(runtime, env_state["r0"], env_state["r_prev"],
+                      cfg.omega, cfg.kappa)
+        ws = workload_stats(env_state["data_keys"], env_state["wr_ratio"])
+        obs = state_vector(idx, read_m, ins_m, runtime, env_state["r_prev"],
+                           env_state["r0"], ws)
     cost = viol["c_m"] + viol["c_r"]
     new_state = dict(env_state)
     new_state["r_prev"] = runtime

@@ -71,4 +71,5 @@ def fused_capture_pallas(cap, new, offsets, interpret: bool = True):
         out_shape=jax.ShapeDtypeStruct((B, H, wide), cap.dtype),
         input_output_aliases={len(FIELD_ORDER) + 1: 0},
         interpret=interpret,
+        name="fused_capture",
     )(*(new[f] for f in FIELD_ORDER), offsets.astype(jnp.int32), cap)

@@ -194,10 +194,12 @@ def predecessor_positions(keys: jax.Array, queries: jax.Array,
     n = keys.shape[0]
     kcfg = kernel if kernel is not None else dispatch.KernelConfig()
     mode = kcfg.resolved() if kcfg.probe_reads else "ref"
-    if mode == "ref":
-        rank = jnp.searchsorted(keys, queries, side="right")
-    else:
-        rank, _ = probe_ranks(keys, queries,
-                              tile=kcfg.probe_tile or auto_tile(n),
-                              mode=mode)
-    return jnp.clip(rank - 1, 0, n - 1)
+    # the whole probe (routing, kernel, overflow chunks) under one scope
+    with jax.named_scope("probe"):
+        if mode == "ref":
+            rank = jnp.searchsorted(keys, queries, side="right")
+        else:
+            rank, _ = probe_ranks(keys, queries,
+                                  tile=kcfg.probe_tile or auto_tile(n),
+                                  mode=mode)
+        return jnp.clip(rank - 1, 0, n - 1)

@@ -46,6 +46,7 @@ from repro.launch.serving.programs import (_batched_admit_keys,
 from repro.launch.serving.stats import (HealthStats, O2Stats, SwapStats,
                                         TenantO2Stats, TenantSwapStats)
 from repro.launch.serving.topology import ServingTopology
+from repro.launch.serving.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -719,20 +720,20 @@ class O2Runtime:
         """Extract the retired episode's capture rows (small gather on
         the serving mesh) into the tenant's ring — the wide fields never
         visit the host."""
-        t0 = time.perf_counter()
-        tenant = self.tenants[req.index_type]
-        if self.fleet is not None:
-            self._touched.add(req.index_type)
-            if tenant.tier != "hot":
-                # a retiring episode is ring traffic: re-page before the
-                # write so the capture rows land on device pages
-                self._promote_hot(tenant)
-        T = len(narrow["reward"])
-        src = np.minimum(np.arange(_pow2_pad(T)), T - 1).astype(np.int32)
-        values = _extract_episode_program(pool.slice)(
-            pool.cap, np.int32(slot), src)
-        tenant.replay.add_episode_values(values, T, **narrow)
-        self.phase_ms["capture"] += 1e3 * (time.perf_counter() - t0)
+        with span("litune.o2.capture", acc=self.phase_ms, key="capture"):
+            tenant = self.tenants[req.index_type]
+            if self.fleet is not None:
+                self._touched.add(req.index_type)
+                if tenant.tier != "hot":
+                    # a retiring episode is ring traffic: re-page before
+                    # the write so the capture rows land on device pages
+                    self._promote_hot(tenant)
+            T = len(narrow["reward"])
+            src = np.minimum(np.arange(_pow2_pad(T)),
+                             T - 1).astype(np.int32)
+            values = _extract_episode_program(pool.slice)(
+                pool.cap, np.int32(slot), src)
+            tenant.replay.add_episode_values(values, T, **narrow)
 
     # -------------------------------------------------------------- tick
     def tick(self, retired: list, pool_key):
@@ -750,42 +751,42 @@ class O2Runtime:
         if paused:
             self.health.degraded_ticks += 1
         if strict and not paused:
-            t0 = time.perf_counter()
-            self._finetune_retired(retired, strict)
-            self.phase_ms["finetune"] += 1e3 * (time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        for req, summary in retired:
-            tenant = self.tenants[req.index_type]
-            pend = self.pending.pop(req.rid, None)
-            if pend is None:
-                # admitted before O2 tracked this tenant (or replayed
-                # after a config swap): skip the window verdict instead
-                # of raising mid-tick, and count it
-                self.pending_missing += 1
-                continue
-            # annotate the request's result with its window verdict, in
-            # the exact shape O2System.tune_window returns; `swapped`
-            # flips in the drain if the assessment wins
-            summary["divergence"] = pend["div"]
-            summary["swapped"] = False
-            if pend["div"]["diverged"] and \
-                    pend["window"] % tenant.cfg.assess_every == 0 \
-                    and not paused and not tenant.quarantined:
-                self.backlog.append((pool_key(req), req, summary, pend))
-        if self.swap_cfg.staged:
-            self._observe_retired(retired)
-            self._advance_trials()
-        if not paused:
-            self._pump_assessments()
-        self.phase_ms["assess"] += 1e3 * (time.perf_counter() - t0)
+            with span("litune.o2.finetune", acc=self.phase_ms,
+                      key="finetune"):
+                self._finetune_retired(retired, strict)
+        with span("litune.o2.assess", acc=self.phase_ms, key="assess"):
+            for req, summary in retired:
+                tenant = self.tenants[req.index_type]
+                pend = self.pending.pop(req.rid, None)
+                if pend is None:
+                    # admitted before O2 tracked this tenant (or replayed
+                    # after a config swap): skip the window verdict
+                    # instead of raising mid-tick, and count it
+                    self.pending_missing += 1
+                    continue
+                # annotate the request's result with its window verdict,
+                # in the exact shape O2System.tune_window returns;
+                # `swapped` flips in the drain if the assessment wins
+                summary["divergence"] = pend["div"]
+                summary["swapped"] = False
+                if pend["div"]["diverged"] and \
+                        pend["window"] % tenant.cfg.assess_every == 0 \
+                        and not paused and not tenant.quarantined:
+                    self.backlog.append((pool_key(req), req, summary,
+                                         pend))
+            if self.swap_cfg.staged:
+                self._observe_retired(retired)
+                self._advance_trials()
+            if not paused:
+                self._pump_assessments()
         if strict:
             # serial-equivalent interleaving: the verdict (and any swap)
             # lands before the next window is admitted
             self.drain(block=True)
         elif not paused:
-            t0 = time.perf_counter()
-            self._finetune_retired(retired, strict)
-            self.phase_ms["finetune"] += 1e3 * (time.perf_counter() - t0)
+            with span("litune.o2.finetune", acc=self.phase_ms,
+                      key="finetune"):
+                self._finetune_retired(retired, strict)
         if self.fleet is not None:
             self._age_tiers()
 
@@ -1085,55 +1086,59 @@ class O2Runtime:
                 time.sleep(5e-4)
                 continue
             self.inflight.popleft()
-            t0 = time.perf_counter()
-            r0s = np.asarray(jax.device_get(entry.r0))
-            rts = np.concatenate(
-                [np.asarray(jax.device_get(r)) for _, r, _ in entry.outs])
-            earls = np.concatenate(
-                [np.asarray(jax.device_get(e)) for _, _, e in entry.outs])
-            deltas: dict[int, float] = {}   # slot column -> delta (ns)
-            wins: dict[int, float] = {}     # winning columns only
-            stops: dict[int, int] = {}
-            tenant = self.tenants[entry.index_type]
-            candidate_ok = None   # lazy health verdict on entry.params
-            for j, (req, summary, pend) in enumerate(entry.items):
-                T = req.budget_steps
-                hit = np.flatnonzero(earls[:T, j])
-                stop = int(hit[0]) + 1 if hit.size else T
-                stops[j] = stop
-                best = _pooled_best(float(r0s[j]), rts[:stop, j])
-                self.assessments += 1
-                delta = summary["best_runtime_ns"] - best
-                deltas[j] = delta
-                if best < summary["best_runtime_ns"]:
-                    wins[j] = delta
-                    if not self.swap_cfg.staged:
-                        # the immediate path — bitwise the pre-pipeline
-                        # behavior: every per-window win swaps, in order
-                        if tenant.quarantined:
-                            continue    # breaker open: serve frozen
-                        if candidate_ok is None:
-                            # swap candidacy gate, once per entry: a
-                            # non-finite/exploded tree never reaches a
-                            # pool, win or not
-                            candidate_ok = self.health.params_healthy(
-                                entry.params)
-                            if not candidate_ok:
-                                self.health.rejected_params += 1
-                                tenant.rejected_params += 1
-                                self._note_bad(tenant)
+            with span("litune.o2.drain", acc=self.phase_ms, key="assess"):
+                self._judge(entry)
+
+    def _judge(self, entry: _PendingAssess):
+        """Fetch one completed assessment's runtimes and settle each of
+        its windows: a win hot-swaps (or, staged, enters the trial)."""
+        r0s = np.asarray(jax.device_get(entry.r0))
+        rts = np.concatenate(
+            [np.asarray(jax.device_get(r)) for _, r, _ in entry.outs])
+        earls = np.concatenate(
+            [np.asarray(jax.device_get(e)) for _, _, e in entry.outs])
+        deltas: dict[int, float] = {}   # slot column -> delta (ns)
+        wins: dict[int, float] = {}     # winning columns only
+        stops: dict[int, int] = {}
+        tenant = self.tenants[entry.index_type]
+        candidate_ok = None   # lazy health verdict on entry.params
+        for j, (req, summary, pend) in enumerate(entry.items):
+            T = req.budget_steps
+            hit = np.flatnonzero(earls[:T, j])
+            stop = int(hit[0]) + 1 if hit.size else T
+            stops[j] = stop
+            best = _pooled_best(float(r0s[j]), rts[:stop, j])
+            self.assessments += 1
+            delta = summary["best_runtime_ns"] - best
+            deltas[j] = delta
+            if best < summary["best_runtime_ns"]:
+                wins[j] = delta
+                if not self.swap_cfg.staged:
+                    # the immediate path — bitwise the pre-pipeline
+                    # behavior: every per-window win swaps, in order
+                    if tenant.quarantined:
+                        continue    # breaker open: serve frozen
+                    if candidate_ok is None:
+                        # swap candidacy gate, once per entry: a
+                        # non-finite/exploded tree never reaches a
+                        # pool, win or not
+                        candidate_ok = self.health.params_healthy(
+                            entry.params)
                         if not candidate_ok:
-                            continue
-                        tenant.swap.candidates += 1
-                        tenant.swap.immediate += 1
-                        tenant.swap.promoted += 1
-                        self.hot_swap(entry.index_type, req,
-                                      window=pend["window"] - 1,
-                                      params=entry.params)
-                        summary["swapped"] = True
-            if wins and self.swap_cfg.staged and not tenant.quarantined:
-                self._judge_staged(entry, deltas, wins, stops, rts)
-            self.phase_ms["assess"] += 1e3 * (time.perf_counter() - t0)
+                            self.health.rejected_params += 1
+                            tenant.rejected_params += 1
+                            self._note_bad(tenant)
+                    if not candidate_ok:
+                        continue
+                    tenant.swap.candidates += 1
+                    tenant.swap.immediate += 1
+                    tenant.swap.promoted += 1
+                    self.hot_swap(entry.index_type, req,
+                                  window=pend["window"] - 1,
+                                  params=entry.params)
+                    summary["swapped"] = True
+        if wins and self.swap_cfg.staged and not tenant.quarantined:
+            self._judge_staged(entry, deltas, wins, stops, rts)
 
     # ------------------------------------------- the swap state machine
     # verdict win -> [CI gate] -> candidate -> [canary trial] -> promoted

@@ -32,7 +32,7 @@ re-trace-free either way.
 """
 from __future__ import annotations
 
-from functools import lru_cache
+from functools import lru_cache, wraps
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +45,18 @@ from repro.core.replay import donate_argnums
 from repro.kernels.fused_tick.ops import fused_capture_core
 from repro.kernels.fused_tick.ref import fused_capture_ref
 from repro.launch.serving.topology import DeviceSlice, _slice_mesh
+
+
+def _scoped(name: str, fn):
+    """`fn` traced under `jax.named_scope(name)`: the tick and reset
+    programs carry their name as their outermost scope, which a profiler
+    trace shows for every one of their operations.  `fn`'s own name stays the
+    jitted module's name (`jit_core`, `jit_scan_core`, ...)."""
+    @wraps(fn)
+    def scoped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return scoped
 
 
 def _pow2_ladder(n: int) -> list[int]:
@@ -130,12 +142,14 @@ def _step_program(slice_: DeviceSlice, net_cfg, env_cfg, et_cfg, k: int,
             return c2, out, cap2
 
         return jax.jit(jax.shard_map(
-            core, mesh=mesh, in_specs=(p_spec, P(ax), P(ax), P(ax), P(ax)),
+            _scoped("pool_tick", core), mesh=mesh,
+            in_specs=(p_spec, P(ax), P(ax), P(ax), P(ax)),
             out_specs=(P(ax), P(None, ax), P(ax)), check_vma=False),
             donate_argnums=donate_argnums(1, 3))
 
     return jax.jit(jax.shard_map(
-        scan_core, mesh=mesh, in_specs=(p_spec, P(ax), P(ax)),
+        _scoped("pool_tick", scan_core), mesh=mesh,
+        in_specs=(p_spec, P(ax), P(ax)),
         out_specs=(P(ax), P(None, ax)), check_vma=False),
         donate_argnums=donate_argnums(1))
 
@@ -151,7 +165,7 @@ def _reset_program(slice_: DeviceSlice, env_cfg):
         return mapped_reset(env_cfg, d, {"reads": r, "inserts": i}, wr)
 
     return jax.jit(jax.shard_map(
-        core, mesh=mesh,
+        _scoped("env_reset", core), mesh=mesh,
         in_specs=(P(ax), P(ax), P(ax), P(ax)),
         out_specs=P(ax), check_vma=False))
 

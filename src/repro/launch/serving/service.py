@@ -46,6 +46,7 @@ from repro.launch.serving.slo import SLOConfig, SLOTracker
 from repro.launch.serving.stats import (PoolStats, SchedulerStats,
                                         ServiceStats)
 from repro.launch.serving.topology import ServingTopology
+from repro.launch.serving.tracing import span
 from repro.runtime import compile_cache
 
 
@@ -366,53 +367,60 @@ class TuningService:
         widths = sorted(set(_pow2_ladder(pool.slots) + [pool.slots]))
         width = next(w for w in widths if w >= m)
         pad = width - m
-        reqs = admits + [admits[0]] * pad
-        data = np.stack([r.data_keys for r in reqs])
-        reads = np.stack([r.workload["reads"] for r in reqs])
-        ins = np.stack([r.workload["inserts"] for r in reqs])
-        wr = np.asarray([r.wr_ratio for r in reqs], np.float32)
-        keys = np.stack([np.asarray(r.key) for r in reqs])
-        assess_keys = None
-        if self.o2rt is not None:
-            keys, assess_keys = self.o2rt.admit_keys(keys)
-        env_states, obs = self._pool_reset_program(pool, width)(
-            data, reads, ins, wr)
-        if width % pool.slice.width != 0:
-            # narrow reset ran on a sub-slice mesh; rehome to host so the
-            # scatter (placed on the pool's slice) accepts it
-            env_states, obs = jax.device_get((env_states, obs))
-
-        if m == pool.slots and pool.carry is None:
-            pool.carry = programs._build_carry_program(
-                pool.slice, pool.net_cfg, pool.slots)(
-                keys, env_states, obs)
-            slots_used = list(range(pool.slots))
-        else:
-            if pool.carry is None:
-                # first admission with a partial wave: seed every slot with
-                # episode 0 so idle slots hold valid (ignored) state
-                es0, obs0 = jax.device_get(
-                    (jax.tree.map(lambda x: x[:1], env_states), obs[:1]))
-                full = jax.tree.map(
-                    lambda x: np.broadcast_to(x, (pool.slots,)
-                                              + x.shape[1:]),
-                    (es0, obs0))
-                pool.carry = programs._build_carry_program(
-                    pool.slice, pool.net_cfg, pool.slots)(
-                    np.broadcast_to(keys[:1], (pool.slots,)
-                                    + keys.shape[1:]), full[0], full[1])
-            slots_used = free[:m]
-            idx = np.asarray(slots_used + [pool.slots] * pad, np.int32)
-            pool.carry = programs._admit_scatter_program(
-                pool.slice, pool.net_cfg, pool.slots)(
-                pool.carry, idx, keys, env_states, obs)
-        r0s = np.asarray(jax.device_get(env_states["r_best"]))
-        now = self.clock()
-        for j, (slot, req) in enumerate(zip(slots_used, admits)):
-            pool.mark_admitted(slot, req, float(r0s[j]))
-            self.slo.on_admit(req, now)
-            if self.o2rt is not None:
-                self.o2rt.observe_admission(req, assess_keys[j])
+        with span("litune.admit"):
+            with span("litune.admit.stage"):
+                reqs = admits + [admits[0]] * pad
+                data = np.stack([r.data_keys for r in reqs])
+                reads = np.stack([r.workload["reads"] for r in reqs])
+                ins = np.stack([r.workload["inserts"] for r in reqs])
+                wr = np.asarray([r.wr_ratio for r in reqs], np.float32)
+                keys = np.stack([np.asarray(r.key) for r in reqs])
+                assess_keys = None
+                if self.o2rt is not None:
+                    keys, assess_keys = self.o2rt.admit_keys(keys)
+            with span("litune.admit.reset"):
+                env_states, obs = self._pool_reset_program(pool, width)(
+                    data, reads, ins, wr)
+                if width % pool.slice.width != 0:
+                    # narrow reset ran on a sub-slice mesh; rehome to host
+                    # so the scatter (placed on the pool's slice) accepts it
+                    env_states, obs = jax.device_get((env_states, obs))
+                if m == pool.slots and pool.carry is None:
+                    pool.carry = programs._build_carry_program(
+                        pool.slice, pool.net_cfg, pool.slots)(
+                        keys, env_states, obs)
+                    slots_used = list(range(pool.slots))
+                else:
+                    if pool.carry is None:
+                        # first admission with a partial wave: seed every
+                        # slot with episode 0 so idle slots hold valid
+                        # (ignored) state
+                        es0, obs0 = jax.device_get(
+                            (jax.tree.map(lambda x: x[:1], env_states),
+                             obs[:1]))
+                        full = jax.tree.map(
+                            lambda x: np.broadcast_to(
+                                x, (pool.slots,) + x.shape[1:]),
+                            (es0, obs0))
+                        pool.carry = programs._build_carry_program(
+                            pool.slice, pool.net_cfg, pool.slots)(
+                            np.broadcast_to(keys[:1], (pool.slots,)
+                                            + keys.shape[1:]),
+                            full[0], full[1])
+                    slots_used = free[:m]
+                    idx = np.asarray(slots_used + [pool.slots] * pad,
+                                     np.int32)
+                    pool.carry = programs._admit_scatter_program(
+                        pool.slice, pool.net_cfg, pool.slots)(
+                        pool.carry, idx, keys, env_states, obs)
+            with span("litune.admit.sync"):
+                r0s = np.asarray(jax.device_get(env_states["r_best"]))
+            now = self.clock()
+            for j, (slot, req) in enumerate(zip(slots_used, admits)):
+                pool.mark_admitted(slot, req, float(r0s[j]))
+                self.slo.on_admit(req, now)
+                if self.o2rt is not None:
+                    self.o2rt.observe_admission(req, assess_keys[j])
 
     def _admit_from_queue(self):
         """Fill free slots with queued requests (FIFO per pool group),
@@ -519,6 +527,10 @@ class TuningService:
         O2 — dispatch the offline learners and the retired windows'
         assessments.  Returns the number of episode-steps of useful
         work."""
+        with span("litune.step"):
+            return self._step()
+
+    def _step(self) -> int:
         if self.o2rt is not None:
             self.o2rt.drain()
         work = 0
@@ -533,7 +545,22 @@ class TuningService:
             min_rem = min(pool.remaining())
             k = max(w for w in _pow2_ladder(self.horizon_cap)
                     if w <= max(min_rem, 1))
-            t_tick = self.clock()
+            with span("litune.tick", k=k, slots=pool.slots,
+                      active=pool.n_active):
+                work += self._tick(pk, pool, k, retired)
+        if self.o2rt is not None:
+            self.o2rt.tick(retired, self._pool_key)
+        self.service_steps += 1
+        self.episode_steps += work
+        return work
+
+    def _tick(self, pk: tuple, pool: _SlotPool, k: int,
+              retired: list) -> int:
+        """Advance `pool` by one K-step program, fetch its narrow fields
+        and retire the episodes that finished; returns the episode-steps
+        of useful work."""
+        t_tick = self.clock()
+        with span("litune.tick.dispatch"):
             # a live canary routes the tick through the per-lane program
             # variant with the pool's mixed params tree — same resident
             # program cache, zero re-traces (pre-bound at pool creation)
@@ -558,24 +585,26 @@ class TuningService:
                 pool.carry, out = program(
                     pool.lane_params if canary else pool.params,
                     pool.carry, pool.noise_dev())
-            # only the narrow fields the serving loop reads cross to the
-            # host — the same five the frozen service transfers
-            fields = ["reward", "runtime_ns", "action", "cost", "early"]
+        # only the narrow fields the serving loop reads cross to the
+        # host — the same five the frozen service transfers
+        fields = ["reward", "runtime_ns", "action", "cost", "early"]
+        with span("litune.tick.fetch"):
             out_host = jax.device_get({f: out[f] for f in fields})
-            # the narrow-field fetch bounds the tick: feed the EDF
-            # feasibility estimate (seconds per episode-step)
-            if warm:
-                self.scheduler.note_tick(
-                    k, self.clock() - t_tick,
-                    in_trial=self._in_trial(pk[0]))
-            if pool.capture and not fused:
-                # unfused fallback (KernelConfig(fused_tick=False)): wide
-                # fields stay on device, appended by the standalone
-                # capture program before collect() advances offsets
-                t0 = time.perf_counter()
+        # the narrow-field fetch bounds the tick: feed the EDF
+        # feasibility estimate (seconds per episode-step)
+        if warm:
+            self.scheduler.note_tick(
+                k, self.clock() - t_tick,
+                in_trial=self._in_trial(pk[0]))
+        if pool.capture and not fused:
+            # unfused fallback (KernelConfig(fused_tick=False)): wide
+            # fields stay on device, appended by the standalone
+            # capture program before collect() advances offsets
+            with span("litune.o2.capture", acc=self.o2rt.phase_ms,
+                      key="capture"):
                 pool.capture_tick(out)
-                self.o2rt.phase_ms["capture"] += \
-                    1e3 * (time.perf_counter() - t0)
+        work = 0
+        with span("litune.tick.collect"):
             for slot, req in enumerate(pool.requests):
                 if req is None:
                     continue
@@ -592,10 +621,6 @@ class TuningService:
                                                      narrow)
                             retired.append((rreq, summary))
                         break
-        if self.o2rt is not None:
-            self.o2rt.tick(retired, self._pool_key)
-        self.service_steps += 1
-        self.episode_steps += work
         return work
 
     def run(self, max_service_steps: int | None = None) -> dict[int, dict]:
