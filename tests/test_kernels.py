@@ -9,7 +9,8 @@ from _hypothesis_compat import given, settings, st
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.index_probe.kernel import probe_pallas
-from repro.kernels.index_probe.ops import (auto_ocap, auto_qcap,
+from repro.kernels.index_probe.ops import (ROUTE_COUNT_MAX_TILES, _route,
+                                           auto_ocap, auto_qcap,
                                            auto_tile, batched_lookup,
                                            predecessor_positions,
                                            probe_ranks)
@@ -288,3 +289,71 @@ def test_auto_qcap_keeps_grouped_buffers_linear():
         qcap = auto_qcap(m, n_tiles)
         assert qcap % 128 == 0
         assert n_tiles * qcap <= 2 * m + 128 * n_tiles
+
+
+# ------------------------------------------- root routing (ops._route)
+ROUTE_SIZES = [40, ROUTE_COUNT_MAX_TILES, ROUTE_COUNT_MAX_TILES + 1]
+
+
+def _route_case(case: str, n_tiles: int, seed: int = 0):
+    """(boundaries [n_tiles] float32, sorted; queries [<= 700] float32)."""
+    rng = np.random.default_rng(seed)
+    if case == "edges":            # distinct boundaries
+        b = np.arange(n_tiles, dtype=np.float32) * 2.0
+        q = np.concatenate([[b[0] - 1, b[0], b[0] + 0.5, b[-1], b[-1] + 1,
+                             1e30], b[n_tiles // 2:][:3] + 1,
+                            rng.choice(b, 300), rng.choice(b, 300) + 1])
+    elif case == "duplicates":     # key runs straddling tile edges
+        b = np.floor(np.arange(n_tiles) / 3).astype(np.float32)
+        v = rng.choice(b, 200)
+        q = np.concatenate([v, v - 0.5, v + 0.5, [-1.0, b[-1] + 1]])
+    elif case == "zeros_inf_nan":  # +-0.0 in either order, +inf boundaries
+        mid = [-1.0, -0.0, 0.0, -0.0, 0.0, 1.0]
+        k = (n_tiles - len(mid) - 3) // 2
+        b = np.concatenate([np.linspace(-100, -2, k), mid,
+                            np.linspace(2, 100, n_tiles - len(mid) - 3 - k),
+                            [np.inf] * 3]).astype(np.float32)
+        q = np.concatenate([[-0.0, 0.0, np.inf, -np.inf, np.nan, -np.nan,
+                             -1.0, 1.0, 100.0, 101.0, -100.0, -101.0],
+                            rng.choice(b, 200)])
+    else:                          # ragged n, last tiles padded with +inf
+        tile, n = 8, 8 * n_tiles - 13
+        keys = np.sort(rng.uniform(0, 1, n)).astype(np.float32)
+        keys = np.pad(keys, (0, 13), constant_values=np.inf)
+        b = keys[::tile]
+        q = np.concatenate([rng.uniform(-0.1, 1.1, 400), [keys[n - 1],
+                            keys[n - 2], np.inf, np.nan, 0.0]])
+    return jnp.asarray(b, jnp.float32), jnp.asarray(q, jnp.float32)
+
+
+@pytest.mark.parametrize("n_tiles", ROUTE_SIZES,
+                         ids=["small", "at_threshold", "above_threshold"])
+@pytest.mark.parametrize("case", ["edges", "duplicates", "zeros_inf_nan",
+                                  "ragged_pad"])
+def test_route_equals_binary_search(case, n_tiles):
+    """The root routing (a count of boundaries <= q up to
+    ROUTE_COUNT_MAX_TILES tiles, a binary search above) is bit for bit
+    the clipped searchsorted, on both sides of the threshold."""
+    b, q = _route_case(case, n_tiles)
+    assert bool(jnp.all(b[1:] >= b[:-1]))
+    got = np.asarray(jax.jit(_route)(b, q))
+    want = np.asarray(jnp.clip(jnp.searchsorted(b, q, side="right") - 1,
+                               0, n_tiles - 1))
+    assert got.dtype == want.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("side", ["count", "search"])
+def test_route_holds_a_loop_only_above_the_threshold(side):
+    """`batched_lookup` at 2^20 keys and the read-heavy mix's 786,432
+    reads, lowered (not compiled): at 2,048 tiles no `while` is left in
+    the program; above ROUTE_COUNT_MAX_TILES tiles the routing's binary
+    search is its one loop."""
+    n, m = 1 << 20, 786_432
+    tile = 512 if side == "count" else n // (2 * ROUTE_COUNT_MAX_TILES)
+    n_tiles = n // tile
+    assert (n_tiles <= ROUTE_COUNT_MAX_TILES) == (side == "count")
+    text = batched_lookup.lower(jax.ShapeDtypeStruct((n,), jnp.float32),
+                                jax.ShapeDtypeStruct((m,), jnp.float32),
+                                tile=tile, mode="ref").as_text()
+    assert text.count("stablehlo.while") == (0 if side == "count" else 1)

@@ -11,6 +11,7 @@
   their ids, and `stats()["o2"]["phase_ms"]` still grows.
 """
 import contextlib
+import dataclasses
 import re
 
 import jax
@@ -23,6 +24,7 @@ from repro.core.ddpg import DDPGConfig
 from repro.core.litune import LITune, LITuneConfig
 from repro.core.o2 import O2Config
 from repro.index.workloads import sample_keys, wr_workload
+from repro.kernels.dispatch import KernelConfig
 from repro.launch.serving import O2ServiceConfig, TuningService
 from repro.launch.serving.config import ServeConfig
 
@@ -115,6 +117,25 @@ def test_reset_program_scopes(pool):
     scopes = _scopes(_compiled(prog, _reset_args(pool)))
     assert {"env_reset", *PHASES[1:]} <= scopes
     assert "pool_tick" not in scopes and "agent" not in scopes
+
+
+def test_step_program_scopes_the_probes_routing(pool):
+    """With the probe kernel on (interpret mode here, compiled on the
+    chip) the tick's routing of reads to tiles carries `probe_route`
+    inside `probe`, which `probe_route_device_ms_per_step` reads."""
+    env_cfg = dataclasses.replace(pool.env_cfg,
+                                  kernel=KernelConfig(mode="interpret"))
+    prog = programs._step_program(pool.slice, pool.net_cfg, env_cfg,
+                                  pool.et_cfg, 2)
+    hlo = _compiled(prog, _tick_args(pool, False, False))
+    # the program's ops (a reducer's body names only its own scope)
+    routed = [name.split("/") for name in
+              re.findall(r'op_name="(jit\([^"]*)"', hlo)
+              if "probe_route" in name.split("/")]
+    assert routed
+    for segs in routed:
+        assert segs.index("pool_tick") < segs.index("env_reads") \
+            < segs.index("probe") < segs.index("probe_route"), segs
 
 
 _LOCATION_TABLES = ("FileNames", "FunctionNames", "FileLocations",
