@@ -177,7 +177,7 @@ def phase_kernels(req: Request, tuner):
           f"tile {tile}, qcap {auto_qcap(m, -(-N_KEYS // tile))}: "
           f"positions equal searchsorted; kernel answered "
           f"{1 - n_over / m:.6f} of reads ({n_over} over capacity, "
-          f"answered by searchsorted)", flush=True)
+          f"answered in overflow chunks)", flush=True)
     # the same shapes on the `osm` set, whose misses pile into the tiles
     # that span gaps between its clusters: the overflow goes through
     # chunks of `auto_ocap`, not through searchsorted over every read
@@ -190,11 +190,11 @@ def phase_kernels(req: Request, tuner):
     want = jnp.searchsorted(keys, reads, side="right").astype(jnp.int32)
     check(bool(jnp.array_equal(ranks, want)),
           "osm probe ranks differ from searchsorted")
-    n_over, ocap = int(n_over), auto_ocap(m)
+    n_over, ocap = int(n_over), auto_ocap(m, tile)
     print(f"[b] probe ({mode}): {m} osm reads x 2^{LOG2_KEYS} keys: "
           f"positions equal searchsorted; kernel answered "
           f"{1 - n_over / m:.6f} of reads ({n_over} over capacity, "
-          f"answered by searchsorted in {-(-n_over // ocap)} chunk(s) of "
+          f"answered in {-(-n_over // ocap)} overflow chunk(s) of "
           f"{ocap})", flush=True)
 
     net = tuner.cfg.net_cfg()

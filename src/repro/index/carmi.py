@@ -90,7 +90,8 @@ def build(keys: jax.Array, p: dict):
 
 def _lines_touched(idx, q, p, kernel=None):
     """Cache lines touched per lookup + the search distance metric.
-    `kernel` gates the predecessor probe (see `alex.run_reads`)."""
+    `kernel` gates the predecessor probe (see `alex.run_reads`); reads
+    and inserts take the same one."""
     pred_leaf = jnp.clip(idx["root_slope"] * q + idx["root_icpt"],
                          0.0, idx["n_leaves"] - 1.0)
     pos = predecessor_positions(idx["keys"], q, kernel=kernel)
@@ -129,11 +130,11 @@ def run_reads(idx, reads, p, kernel=None):
     }
 
 
-def run_inserts(idx, inserts, p):
+def run_inserts(idx, inserts, p, kernel=None):
     in_domain = inserts <= idx["kmax"]
     n_ood = jnp.sum(~in_domain).astype(jnp.float32)
     q_in = jnp.where(in_domain, inserts, idx["kmin"])
-    ns, dist, _, leaf = _lines_touched(idx, q_in, p)
+    ns, dist, _, leaf = _lines_touched(idx, q_in, p, kernel=kernel)
     w_in = in_domain.astype(jnp.float32)
     add = jnp.zeros(MAX_LEAVES).at[leaf].add(w_in)
     cnt1 = idx["cnt"] + add

@@ -68,6 +68,8 @@ def evaluate_params(cfg: EnvConfig, params_raw: dict, data_keys, workload,
     """
     mod = _backend(cfg.index_type)
     read_args = () if cfg.index_type == "alex" else (params_raw,)
+    # CARMI probes for its inserts as for its reads, under the same posture
+    insert_kw = {} if cfg.index_type == "alex" else {"kernel": cfg.kernel}
     with jax.named_scope("env_build"):
         idx = mod.build(data_keys, params_raw)
     with jax.named_scope("env_reads"):
@@ -75,7 +77,7 @@ def evaluate_params(cfg: EnvConfig, params_raw: dict, data_keys, workload,
                                         kernel=cfg.kernel)
     with jax.named_scope("env_inserts"):
         idx, ins_ns, ins_m = mod.run_inserts(idx, workload["inserts"],
-                                             params_raw)
+                                             params_raw, **insert_kw)
     with jax.named_scope("env_observe"):
         n_ops = workload["reads"].shape[0] + workload["inserts"].shape[0]
         # avg ns per operation (paper metric)

@@ -12,10 +12,10 @@ callers the compiled kernel without any per-callsite flags.
 
 `probe_ranks` is the exact op built on it: the grouped buffers stay
 O(n + m) (`qcap` is about twice a tile's mean query load), and the
-queries that overflow a tile are answered by `searchsorted` in bounded
-chunks (`auto_ocap`, an eighth of the queries), addressed through the
-per-tile overflow counts.  The overflow is counted, so a caller can
-report the share the kernel answered.
+queries that overflow a tile are answered in bounded chunks (`auto_ocap`,
+an eighth of the queries or fewer), addressed through the per-tile
+overflow counts, each by counting the keys of its own tile.  The overflow is
+counted, so a caller can report the share the kernel answered.
 
 `predecessor_positions` is the env-facing wrapper the index simulators'
 ``run_reads`` hot paths call: predecessor *positions* (clipped rank-1),
@@ -64,19 +64,26 @@ def _round_up(x: int, m: int) -> int:
 ROUTE_COUNT_MAX_TILES = 1 << 17
 
 
+def _count_le(sorted_vals, queries):
+    """#(sorted_vals <= q) for each of `queries`, as `searchsorted(side=
+    "right")` for every non-NaN q: counted up to ROUTE_COUNT_MAX_TILES
+    values, binary-searched above."""
+    if sorted_vals.shape[0] <= ROUTE_COUNT_MAX_TILES:
+        return jnp.sum(sorted_vals[:, None] <= queries[None, :], axis=0,
+                       dtype=jnp.int32)
+    return jnp.searchsorted(sorted_vals, queries,
+                            side="right").astype(jnp.int32)
+
+
 def _route(boundaries, queries):
     """Tile of each query: clip(#(boundaries <= q) - 1, 0, T - 1), in
     `searchsorted`'s order (a NaN query after every boundary; -0.0 equals
     0.0), for sorted `boundaries` [T] and any `queries` [m]."""
     n_tiles = boundaries.shape[0]
     with jax.named_scope("probe_route"):
-        if n_tiles <= ROUTE_COUNT_MAX_TILES:
-            count = jnp.sum(boundaries[:, None] <= queries[None, :], axis=0,
-                            dtype=jnp.int32)
-            count = jnp.where(jnp.isnan(queries), n_tiles, count)
-        else:
-            count = jnp.searchsorted(boundaries, queries, side="right")
-        return jnp.clip(count - 1, 0, n_tiles - 1).astype(jnp.int32)
+        count = jnp.where(jnp.isnan(queries), n_tiles,
+                          _count_le(boundaries, queries))
+        return jnp.clip(count - 1, 0, n_tiles - 1)
 
 
 def auto_qcap(m: int, n_tiles: int) -> int:
@@ -135,7 +142,7 @@ def _grouped_ranks(keys, queries, tile: int, qcap: int, mode: str):
     # +inf query counted)
     local = pos[t_sorted, jnp.minimum(pos_in_tile, qcap - 1)]
     ranks = jnp.where(keep, jnp.minimum(t_sorted * tile + local, n), -1)
-    return order, ranks, keep, q_sorted, counts, starts, qcap
+    return order, ranks, keep, q_sorted, counts, starts, qcap, key_tiles
 
 
 @partial(jax.jit, static_argnames=("tile", "qcap", "mode"))
@@ -156,44 +163,59 @@ def batched_lookup(keys: jax.Array, queries: jax.Array, tile: int = 512,
             jnp.zeros((m,), bool).at[order].set(~keep))
 
 
-def auto_ocap(m: int) -> int:
-    """Overflow chunk: an eighth of the queries, lane-aligned.  Queries
-    that hit keys spread over tiles like the keys do and stay within
-    `qcap`; those that pile into one tile are misses landing in a gap
-    between keys, and the SOSD-style workloads send a tenth of their
-    reads as misses (`index/workloads.py`), so one chunk holds them."""
-    return min(m, _round_up(max(m // 8, 128), 128))
+# Keys an overflow chunk copies, its queries' tiles: 16 MiB of float32
+OCAP_MAX_KEYS = 1 << 22
+
+
+def auto_ocap(m: int, tile: int = 512) -> int:
+    """Overflow chunk: an eighth of the queries, lane-aligned, and at
+    most OCAP_MAX_KEYS / tile of them, since each copies its tile's
+    keys.  Queries that hit keys spread over tiles like the keys do and
+    stay within `qcap`; those that pile into one tile are misses landing
+    in a gap between keys (the SOSD-style workloads send a tenth of
+    their reads as misses, `index/workloads.py`)."""
+    return min(m, _round_up(max(m // 8, 128), 128),
+               max(OCAP_MAX_KEYS // tile, 128))
 
 
 @partial(jax.jit, static_argnames=("tile", "qcap", "mode"))
 def probe_ranks(keys: jax.Array, queries: jax.Array, tile: int = 512,
                 qcap: int = 0, mode: str | None = None):
     """Exact ranks #(keys <= q) through the grouped probe.  Queries that
-    overflowed their tile's capacity are answered by `searchsorted` in
-    chunks of `auto_ocap(m)`: chunk slot s maps to its sorted position
-    through the per-tile overflow counts, so no [m]-wide compaction or
-    search runs, and no chunk runs when none overflowed.
+    overflowed their tile's capacity are answered in chunks of
+    `auto_ocap(m, tile)`: chunk slot s finds its tile by counting the
+    per-tile overflow ends <= s, and its sorted position from that
+    tile's offset, so no [m]-wide compaction or search runs, and no
+    chunk runs when none overflowed.  Each chunk query counts the keys
+    <= q in its own tile, as the kernel does for the queries within
+    capacity (a `searchsorted` over all keys would gather log2(n) times
+    per query).
     Returns (ranks [m] int32, n_overflow int32)."""
-    order, ranks, _, q_sorted, counts, starts, qcap = _grouped_ranks(
-        keys, queries, tile, qcap, mode)
+    (order, ranks, _, q_sorted, counts, starts, qcap,
+     key_tiles) = _grouped_ranks(keys, queries, tile, qcap, mode)
+    n = keys.shape[0]
     m = queries.shape[0]
-    ocap = auto_ocap(m)
-    keys = keys.astype(jnp.float32)
+    ocap = auto_ocap(m, tile)
+    n_tiles = counts.shape[0]
     over = jnp.maximum(counts - qcap, 0)                    # [T]
     ends = jnp.cumsum(over)
     n_over = ends[-1].astype(jnp.int32)
+    # tile t's overflow slots [ends[t] - over[t], ends[t]) sit at sorted
+    # positions from starts[t] + qcap on: position = slot + offset[t]
+    offset = starts + qcap - (ends - over)
 
     def chunk(c, r):
-        s = c * ocap + jnp.arange(ocap)
-        t = jnp.minimum(jnp.searchsorted(ends, s, side="right"),
-                        counts.shape[0] - 1)
-        j = jnp.where(s < n_over, starts[t] + qcap + s - (ends[t] - over[t]),
-                      m)                                    # m: dropped
+        s = c * ocap + jnp.arange(ocap, dtype=jnp.int32)
+        t = jnp.minimum(_count_le(ends, s), n_tiles - 1)
+        j = jnp.where(s < n_over, s + offset[t], m)         # m: dropped
         q = q_sorted.at[j].get(mode="fill", fill_value=0)
-        return r.at[j].set(jnp.searchsorted(keys, q, side="right"),
-                           mode="drop")
+        local = jnp.sum(key_tiles[t] <= q[:, None], axis=1,
+                        dtype=jnp.int32)
+        return r.at[j].set(jnp.minimum(t * tile + local, n), mode="drop")
 
-    ranks = jax.lax.fori_loop(0, (n_over + ocap - 1) // ocap, chunk, ranks)
+    with jax.named_scope("probe_overflow"):
+        ranks = jax.lax.fori_loop(0, (n_over + ocap - 1) // ocap, chunk,
+                                  ranks)
     return jnp.zeros((m,), jnp.int32).at[order].set(ranks), n_over
 
 

@@ -250,8 +250,8 @@ def test_predecessor_positions_ragged_falls_back(rng_key):
 @pytest.mark.parametrize("n_crammed", [200, 400])
 @pytest.mark.parametrize("mode", ["ref", "interpret"])
 def test_probe_ranks_resolves_overflow_exactly(mode, n_crammed, rng_key):
-    """Queries beyond a tile's bounded capacity are answered by
-    searchsorted in overflow chunks and counted; every rank stays exact —
+    """Queries beyond a tile's bounded capacity are answered in overflow
+    chunks and counted; every rank stays exact —
     in one chunk (200 crammed) and in several (400 crammed)."""
     tile = 128
     keys = jnp.sort(jax.random.uniform(rng_key, (8 * tile,)))
@@ -277,6 +277,27 @@ def test_probe_ranks_nonfinite_queries(mode):
                                      jnp.array([jnp.inf] * 3)]))
     q = jnp.array([jnp.nan, jnp.inf, -jnp.inf, 0.0, -0.0, 1.0, 5.0])
     ranks, _ = probe_ranks(keys, q, tile=128, mode=mode)
+    np.testing.assert_array_equal(
+        np.asarray(ranks), np.asarray(jnp.searchsorted(keys, q, side="right")))
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+def test_probe_ranks_overflow_at_the_edges(mode):
+    """Overflow chunks count each query's own tile exactly where that is
+    delicate: queries below the first key (tile 0 by clipping), on a key
+    repeated across a tile edge, between tiles, and +inf and NaN piled
+    into a last tile padded with +inf keys; several chunks each."""
+    tile = 128
+    base = np.linspace(0.0, 1.0, 7 * tile - 37, dtype=np.float32)
+    base[tile - 20:tile + 20] = base[tile - 20]     # spans tiles 0 and 1
+    keys = jnp.asarray(np.sort(base))
+    piles = [np.full(300, v, np.float32) for v in
+             (-1.0, base[tile - 20], base[3 * tile] + 1e-4, np.inf,
+              np.nan, base[-1])]
+    spread = np.linspace(-0.1, 1.1, 200, dtype=np.float32)
+    q = jnp.asarray(np.concatenate(piles + [spread]))
+    ranks, n_over = probe_ranks(keys, q, tile=tile, qcap=128, mode=mode)
+    assert int(n_over) > 2 * auto_ocap(q.shape[0])
     np.testing.assert_array_equal(
         np.asarray(ranks), np.asarray(jnp.searchsorted(keys, q, side="right")))
 

@@ -22,7 +22,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.replay import wide_dim
-from repro.index import alex
 from repro.index import env as E
 from repro.index.features import STATE_DIM
 from repro.kernels.dispatch import KernelConfig
@@ -96,10 +95,11 @@ def test_fused_capture_compiles_at_serving_width(one_chip, k, h):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_env_step_compiles_at_2e20_within_hbm(one_chip):
+@pytest.mark.parametrize("index_type", ["alex", "carmi"])
+def test_env_step_compiles_at_2e20_within_hbm(one_chip, index_type):
     """One read-heavy env step at 2^20 keys (the largest of the three
     mixes' read counts) with the compiled probe fits one chip's HBM."""
-    cfg = E.EnvConfig(index_type="alex", kernel=COMPILED)
+    cfg = E.EnvConfig(index_type=index_type, kernel=COMPILED)
     n_reads = READS["read_heavy"]
     f32 = lambda shape=(): _sds(one_chip, shape)             # noqa: E731
     env_state = {
@@ -107,7 +107,7 @@ def test_env_step_compiles_at_2e20_within_hbm(one_chip):
         "inserts": f32((N_KEYS - n_reads,)), "wr_ratio": f32(),
         "r0": f32(), "r_prev": f32(), "r_best": f32(),
         "t": _sds(one_chip, (), jnp.int32), "cum_cost": f32()}
-    action = f32((len(alex.PARAM_SPACE),))
+    action = f32((cfg.space.dim,))
     fn = jax.jit(E.step_core, static_argnames=("cfg",))
     compiled = fn.lower(cfg, env_state, action).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -138,6 +138,27 @@ def test_step_program_scopes_the_probes_routing(pool):
             < segs.index("probe") < segs.index("probe_route"), segs
 
 
+def test_step_program_scopes_the_leaf_fit_and_the_overflow(pool):
+    """The exact leaf and root fits carry `leaf_fit` inside `env_build`,
+    and the probe's overflow chunks `probe_overflow` inside `probe`
+    (kernel on, as above), which `leaf_fit_device_ms_per_step` and
+    `probe_overflow_device_ms_per_step` read."""
+    env_cfg = dataclasses.replace(pool.env_cfg,
+                                  kernel=KernelConfig(mode="interpret"))
+    prog = programs._step_program(pool.slice, pool.net_cfg, env_cfg,
+                                  pool.et_cfg, 2)
+    hlo = _compiled(prog, _tick_args(pool, False, False))
+    paths = [name.split("/") for name in
+             re.findall(r'op_name="(jit\([^"]*)"', hlo)]
+    for scopes in (("pool_tick", "env_build", "leaf_fit"),
+                   ("pool_tick", "env_reads", "probe", "probe_overflow")):
+        found = [segs for segs in paths if scopes[-1] in segs]
+        assert found, scopes
+        for segs in found:
+            at = [segs.index(s) for s in scopes]
+            assert at == sorted(at), segs
+
+
 _LOCATION_TABLES = ("FileNames", "FunctionNames", "FileLocations",
                     "StackFrames")
 
