@@ -59,6 +59,68 @@ def test_approx_fit_never_better_than_exact_on_average(seed):
     assert float(jnp.mean(err_a)) >= float(jnp.mean(err_e)) - 1.0
 
 
+def _segment_ids(keys, n_segs, kind):
+    """Rank-based (equal counts), equi-width (equal key ranges: empty
+    segments where the keys thin out) or every other id (half empty)."""
+    n = keys.shape[0]
+    if kind == "width":
+        w = (keys - keys[0]) / (keys[-1] - keys[0]) * n_segs
+        return np.minimum(w, n_segs - 1).astype(np.int32)
+    seg = np.minimum(np.arange(n) * n_segs // n, n_segs - 1)
+    if kind == "sparse":
+        seg = np.minimum(2 * (seg // 2), n_segs - 1)
+    return seg.astype(np.int32)
+
+
+@pytest.mark.parametrize("n,n_segs,kind", [
+    (1000, 1, "rank"), (70001, 1, "rank"), (3001, 8, "rank"),
+    (3001, 8, "width"), (70001, 512, "rank"), (70001, 512, "width"),
+    (5000, 1024, "rank"), (20000, 1024, "width"), (2048, 1024, "sparse")])
+def test_segment_sums_and_counts_match_float64(n, n_segs, kind):
+    """The fits' per-segment sums (one-hot contractions over blocks of
+    keys, three bfloat16 pieces per float32 term) within 1e-6 of float64
+    sums, relative to each segment's sum of magnitudes; counts exact.
+    `n` is never a whole number of blocks here."""
+    rng = np.random.default_rng(n * n_segs)
+    keys = np.sort(rng.lognormal(0.0, 2.0, n)).astype(np.float32)
+    keys = (keys - keys[0]) / (keys[-1] - keys[0])
+    seg = _segment_ids(keys, n_segs, kind)
+    terms = [keys, keys * keys, (keys - 0.01) * rng.normal(size=n)]
+    terms = [t.astype(np.float32) for t in terms]
+    got = jax.jit(lambda s, *t: lm._segment_sums(s, n_segs, *t))(seg, *terms)
+    for g, t in zip(got, terms):
+        want = np.zeros(n_segs)
+        np.add.at(want, seg, t.astype(np.float64))
+        scale = np.zeros(n_segs)
+        np.add.at(scale, seg, np.abs(t.astype(np.float64)))
+        assert np.all(np.abs(np.asarray(g, np.float64) - want)
+                      <= 1e-6 * scale)
+    want_cnt = np.bincount(seg, minlength=n_segs)
+    np.testing.assert_array_equal(
+        np.asarray(lm._segment_counts(jnp.asarray(seg), n_segs,
+                                      jnp.float32)), want_cnt)
+    _, _, cnt = lm.fit_segments_exact(jnp.asarray(keys), jnp.asarray(seg),
+                                      n_segs)
+    np.testing.assert_array_equal(np.asarray(cnt), want_cnt)
+    if kind != "rank":
+        assert np.any(want_cnt == 0)
+
+
+@pytest.mark.parametrize("fit", ["leaves", "root"])
+def test_fits_lower_without_scatter(fit):
+    """Neither the leaf fit (2^14 keys, 512 segments) nor the root fit
+    takes a scatter: the TPU runs a scatter-add one key after another."""
+    n = 1 << 14
+    keys = jax.ShapeDtypeStruct((n,), jnp.float32)
+    if fit == "root":
+        lowered = jax.jit(lm.fit_root).lower(keys)
+    else:
+        seg = jax.ShapeDtypeStruct((n,), jnp.int32)
+        lowered = jax.jit(lambda k, s: lm.fit_segments_exact(k, s, 512)
+                          ).lower(keys, seg)
+    assert "scatter" not in lowered.as_text()
+
+
 # ------------------------------------------------------------------ alex
 def test_alex_search_exact_on_uniform(rng_key):
     """Near-linear data + exact fits => tiny search distances."""
